@@ -20,6 +20,7 @@ from repro.sim.policies import PolicyConfig, ec2moe_stages, make_requests
 from repro.sim.simulator import Link, SimRequest, Stage, poisson_arrivals, simulate
 
 from benchmarks.common import eval_tiny, tiny_switch, train_tiny
+from repro.launch.cache import enable_compile_cache
 
 
 def accuracy_ablation(E: int = 16, steps: int = 300, seed: int = 0) -> Dict:
@@ -123,6 +124,7 @@ def perf_ablation(E: int = 16, op_rate: float = 8.0, sat_rate: float = 60.0,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--out", default="bench_ablation.json")

@@ -72,6 +72,7 @@ from repro.core.hardware import DeviceProfile, DeviceState
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.stream import EndCloudServingEngine
+from repro.launch.cache import enable_compile_cache
 
 # Device profiles calibrated to smoke-model scale (the paper-testbed profiles
 # paired with a ~100k-param smoke model put every split in the all-cloud
@@ -676,6 +677,7 @@ def run_spec(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_decode_pipeline.json")
     ap.add_argument("--rank", type=int, default=0)

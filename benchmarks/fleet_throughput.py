@@ -55,6 +55,7 @@ from repro.core.hardware import DeviceProfile
 from repro.models.model import build_model
 from repro.serving.common import Request
 from repro.serving.fleet import FleetServingEngine
+from repro.launch.cache import enable_compile_cache
 
 # Smoke-scale fleet: three device classes plus one straggler, against a
 # deliberately *scarce* shared cloud (the fleet regime the paper's
@@ -624,6 +625,7 @@ def _run_quant_fleet(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_fleet.json")
     ap.add_argument("--n-requests", type=int, default=48)

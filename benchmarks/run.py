@@ -23,6 +23,8 @@ import json
 import os
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 
 def _timed(fn):
     t0 = time.perf_counter()
@@ -99,6 +101,7 @@ BENCHES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():
         try:

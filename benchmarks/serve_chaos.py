@@ -66,6 +66,7 @@ from repro.serving.loadgen import (
 )
 
 from benchmarks.fleet_throughput import CLOUD, FLEET_PROFILES
+from repro.launch.cache import enable_compile_cache
 
 # Fault counters every chaos report carries (and serve_load reports as
 # all-zero on its fault-free runs).
@@ -320,6 +321,7 @@ def run(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n-requests", type=int, default=600)
     ap.add_argument("--rate-rps", type=float, default=800.0)

@@ -57,6 +57,7 @@ from repro.serving.loadgen import (
 
 from benchmarks.fleet_throughput import CLOUD, FLEET_PROFILES
 from benchmarks.serve_chaos import FAULT_KEYS
+from repro.launch.cache import enable_compile_cache
 
 
 def _build_engine(model, params, *, n_lanes: int, max_batch: int,
@@ -192,6 +193,7 @@ def run(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n-requests", type=int, default=1000)
     ap.add_argument("--rate-rps", type=float, default=0.0)

@@ -26,6 +26,7 @@ from benchmarks.common import (
     tiny_switch,
     train_tiny,
 )
+from repro.launch.cache import enable_compile_cache
 
 
 def run(
@@ -67,6 +68,7 @@ def run(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--experts", default="8,16,32,64")
     ap.add_argument("--datasets", default="glue_proxy,squad_proxy")

@@ -13,9 +13,11 @@ from repro.core.gating import group_gate_probs
 from repro.core.hardware import PROFILES, DeviceState
 from repro.core.selection import end_mask_for
 from repro.models.model import build_model, make_dummy_batch
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # A reduced qwen3-moe (the HL-GGN flagship arch: 8 experts in 4 groups here)
     cfg = smoke_config(get_config("qwen3-moe-235b-a22b"))
     print(f"arch={cfg.name}  experts={cfg.moe.num_experts} "

@@ -23,9 +23,11 @@ from repro.data.pipeline import DataConfig, batches, eval_accuracy
 from repro.serving.endcloud import EndCloudPipeline
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.stream import EndCloudServingEngine
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     args = ap.parse_args()

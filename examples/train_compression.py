@@ -16,9 +16,11 @@ import jax.numpy as jnp
 from benchmarks.common import eval_tiny, tiny_switch, train_tiny
 from repro.configs import CompressionConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rank", type=int, default=16)
     ap.add_argument("--steps", type=int, default=200)

@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -130,18 +131,9 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
-            _vmem_scratch((bq, 1)),
-            _vmem_scratch((bq, 1)),
-            _vmem_scratch((bq, hd)),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-
-
-def _vmem_scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover - CPU interpret fallback
-        return pl.VMEM(shape, jnp.float32)

@@ -842,7 +842,7 @@ class EndCloudServingEngine(SlotEngineBase):
             }
             return z, new_pages, stats
 
-        def cloud_step(cloud_params, z, pages, table, lengths):
+        def cloud_logits(cloud_params, z, pages, table, lengths):
             z = wire_decode(z)
             angles = decode_angles(lengths, z.shape[0])
             x = comp.decode_1d(codec, z) if compress else z
@@ -851,7 +851,12 @@ class EndCloudServingEngine(SlotEngineBase):
                 cloud_params, x, cfg, topo, angles, pages, lengths,
                 expert_mask=None, page_table=table, page_size=ps,
             )
-            logits = transformer.lm_logits(cloud_params, cfg, x)[:, 0]
+            return transformer.lm_logits(cloud_params, cfg, x)[:, 0], new_pages
+
+        def cloud_step(cloud_params, z, pages, table, lengths):
+            logits, new_pages = cloud_logits(
+                cloud_params, z, pages, table, lengths
+            )
             # greedy ids resolved in-trace: one int32 per row crosses to the
             # host (batched per tick) instead of a [B, V] logits row
             return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
@@ -882,17 +887,31 @@ class EndCloudServingEngine(SlotEngineBase):
             z = wire_encode(comp.encode_1d(codec, x) if compress else x)
             return z, new_pages
 
-        def cloud_prefill_chunk(cloud_params, z, pages, table, start, n_valid):
+        def cloud_prefill_hidden(cloud_params, z, pages, table, start,
+                                 n_valid):
             z = wire_decode(z)
             B, C = z.shape[:2]
             positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
             angles = chunk_angles(positions)
             x = comp.decode_1d(codec, z) if compress else z
             x = x.astype(act)
-            x, new_pages = transformer.apply_stack_prefill_chunk(
+            return transformer.apply_stack_prefill_chunk(
                 cloud_params, x, cfg, topo, angles, pages, table,
                 positions, n_valid, ps, expert_mask=None,
             )
+
+        def cloud_prefill_logits(cloud_params, z, pages, table, start,
+                                 n_valid):
+            x, new_pages = cloud_prefill_hidden(
+                cloud_params, z, pages, table, start, n_valid
+            )
+            return transformer.lm_logits(cloud_params, cfg, x), new_pages
+
+        def cloud_prefill_chunk(cloud_params, z, pages, table, start, n_valid):
+            x, new_pages = cloud_prefill_hidden(
+                cloud_params, z, pages, table, start, n_valid
+            )
+            B = x.shape[0]
             x_last = x[jnp.arange(B), jnp.maximum(n_valid - 1, 0)][:, None]
             logits = transformer.lm_logits(cloud_params, cfg, x_last)[:, 0]
             return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
@@ -916,6 +935,13 @@ class EndCloudServingEngine(SlotEngineBase):
         self._cloud_prefill_chunk = counted(
             "cloud_prefill_chunk", cloud_prefill_chunk
         )
+        # the cloud stages' bodies before the in-trace argmax, un-jitted and
+        # never called by the served path: logit-level checks (chip_smoke.py)
+        # run the end stages above with these to compare against a reference
+        self.cloud_logit_fns = {
+            "cloud_step": cloud_logits,
+            "cloud_prefill_chunk": cloud_prefill_logits,
+        }
         # speculative stage fns close over the same codec/mask/split state:
         # drop the per-k cache so they rebuild lazily against the new plan
         self._spec_fns = {}
