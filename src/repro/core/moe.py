@@ -122,7 +122,8 @@ def moe_naive(params: Dict, x: jax.Array, cfg, expert_mask=None):
     """Oracle: every expert evaluates every token; combine by gate weight."""
     m = cfg.moe
     T = x.shape[0]
-    out = gating.gate(params["gate"], x, m, expert_mask)
+    with jax.named_scope("gate"):
+        out = gating.gate(params["gate"], x, m, expert_mask)
     cw = jnp.zeros((T, m.num_experts), jnp.float32)
     cw = cw.at[jnp.arange(T)[:, None], out.topk_idx].set(
         out.topk_weight.astype(jnp.float32)
@@ -155,7 +156,8 @@ def moe_sorted(params: Dict, x: jax.Array, cfg, expert_mask=None):
     m = cfg.moe
     T, d = x.shape
     k = m.top_k
-    out = gating.gate(params["gate"], x, m, expert_mask)
+    with jax.named_scope("gate"):
+        out = gating.gate(params["gate"], x, m, expert_mask)
     flat_e = out.topk_idx.reshape(-1)  # [T*k]
     tok = jnp.arange(T * k) // k
     rows = x[tok]
@@ -165,7 +167,8 @@ def moe_sorted(params: Dict, x: jax.Array, cfg, expert_mask=None):
         sent = comp.roundtrip_1d(codec, rows).astype(x.dtype)
         aux["recon_loss"] = comp.recon_loss(rows, sent)
         rows = sent
-    y_rows = _sorted_expert_ffn(rows, flat_e, m.num_experts, params, cfg.act)
+    with jax.named_scope("experts"):
+        y_rows = _sorted_expert_ffn(rows, flat_e, m.num_experts, params, cfg.act)
     if codec is not None:
         back = comp.roundtrip_1d(codec, y_rows).astype(y_rows.dtype)
         aux["recon_loss"] = aux["recon_loss"] + comp.recon_loss(y_rows, back)
@@ -203,7 +206,8 @@ def moe_resident(params: Dict, x: jax.Array, cfg, expert_mask=None):
         eff_mask = jnp.logical_and(jnp.asarray(expert_mask, bool), resident_ok)
     else:
         eff_mask = resident_ok
-    out = gating.gate(params["gate"], x, m, eff_mask)
+    with jax.named_scope("gate"):
+        out = gating.gate(params["gate"], x, m, eff_mask)
     flat_e = out.topk_idx.reshape(-1)  # [T*k]
     slots = slot_of[flat_e]  # [T*k] -> garbage slot S for non-residents
     tok = jnp.arange(T * k) // k
@@ -214,23 +218,24 @@ def moe_resident(params: Dict, x: jax.Array, cfg, expert_mask=None):
         sent = comp.roundtrip_1d(codec, rows).astype(x.dtype)
         aux["recon_loss"] = comp.recon_loss(rows, sent)
         rows = sent
-    # gather ONLY the resident slabs (plus the shared zero garbage row)
-    store = res["store"]
-    wi = store["wi"][ids]  # [S+1, d, f]
-    wg = store["wg"][ids] if "wg" in store else None
-    wo = store["wo"][ids]  # [S+1, f, d]
-    if "wi_scale" in store:
-        # int8 slab store: the HBM gather reads int8 codes; dequantize just
-        # the S+1 gathered slabs (per-output-column fp32 scales, exact
-        # modulo the int8 grid) before the grouped GEMM
-        wi = wi.astype(jnp.float32) * store["wi_scale"][ids][:, None, :]
-        wo = wo.astype(jnp.float32) * store["wo_scale"][ids][:, None, :]
-        if wg is not None:
-            wg = wg.astype(jnp.float32) * store["wg_scale"][ids][:, None, :]
-    order = jnp.argsort(slots)
-    gs = jnp.bincount(slots, length=S + 1).astype(jnp.int32)
-    y_sorted = _grouped_mlp(rows[order], gs, wi, wg, wo, cfg.act)
-    y_rows = jnp.zeros_like(y_sorted).at[order].set(y_sorted)
+    with jax.named_scope("experts"):
+        # gather ONLY the resident slabs (plus the shared zero garbage row)
+        store = res["store"]
+        wi = store["wi"][ids]  # [S+1, d, f]
+        wg = store["wg"][ids] if "wg" in store else None
+        wo = store["wo"][ids]  # [S+1, f, d]
+        if "wi_scale" in store:
+            # int8 slab store: the HBM gather reads int8 codes; dequantize
+            # just the S+1 gathered slabs (per-output-column fp32 scales,
+            # exact modulo the int8 grid) before the grouped GEMM
+            wi = wi.astype(jnp.float32) * store["wi_scale"][ids][:, None, :]
+            wo = wo.astype(jnp.float32) * store["wo_scale"][ids][:, None, :]
+            if wg is not None:
+                wg = wg.astype(jnp.float32) * store["wg_scale"][ids][:, None, :]
+        order = jnp.argsort(slots)
+        gs = jnp.bincount(slots, length=S + 1).astype(jnp.int32)
+        y_sorted = _grouped_mlp(rows[order], gs, wi, wg, wo, cfg.act)
+        y_rows = jnp.zeros_like(y_sorted).at[order].set(y_sorted)
     if codec is not None:
         back = comp.roundtrip_1d(codec, y_rows).astype(y_rows.dtype)
         aux["recon_loss"] = aux["recon_loss"] + comp.recon_loss(y_rows, back)
@@ -415,6 +420,15 @@ def apply_moe(
     expert_mask: Optional[jax.Array] = None,
     train: bool = True,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The MoE layer, every operation of it under the ``moe`` name scope
+    (``moe/gate`` for routing, ``moe/experts`` for the expert FFNs on the
+    single-shard paths), so a profile finds its device time by name."""
+    with jax.named_scope("moe"):
+        return _apply_moe(params, x, cfg, topo, expert_mask=expert_mask,
+                          train=train)
+
+
+def _apply_moe(params, x, cfg, topo, *, expert_mask, train):
     m = cfg.moe
     impl = cfg.moe_impl
     if impl == "auto":

@@ -141,17 +141,18 @@ def init_params(key, cfg) -> Dict:
 
 
 def _self_attention_full(p, h, cfg, angles, causal):
-    q, k, v = attn.project_qkv(p, h, cfg, angles)
-    o = attn.flash_attention(
-        q,
-        k,
-        v,
-        causal=causal,
-        window=cfg.sliding_window if causal else None,
-        q_chunk=cfg.attn_chunk_q,
-        kv_chunk=cfg.attn_chunk_kv,
-    )
-    return attn.output_proj(p, o), (k, v)
+    with jax.named_scope("attention"):
+        q, k, v = attn.project_qkv(p, h, cfg, angles)
+        o = attn.flash_attention(
+            q,
+            k,
+            v,
+            causal=causal,
+            window=cfg.sliding_window if causal else None,
+            q_chunk=cfg.attn_chunk_q,
+            kv_chunk=cfg.attn_chunk_kv,
+        )
+        return attn.output_proj(p, o), (k, v)
 
 
 def _self_attention_seqp(p, h, cfg, topo, angles, causal):
@@ -284,7 +285,8 @@ def apply_layer_full(
                 p["moe"], h, cfg, topo, expert_mask=expert_mask, train=train
             )
         else:
-            y = apply_mlp(p["ffn"], h, cfg.act)
+            with jax.named_scope("mlp"):
+                y = apply_mlp(p["ffn"], h, cfg.act)
         x = x + y
     return x, aux, cache_entry
 
@@ -316,39 +318,47 @@ def apply_layer_decode(
     new_entry = dict(cache_entry)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if spec.kind == "attn":
-        q, k, v = attn.project_qkv(p["attn"], h, cfg, angles)
+        with jax.named_scope("attention"):
+            q, k, v = attn.project_qkv(p["attn"], h, cfg, angles)
         if page_table is not None and "k_scale" in cache_entry:
             # int8 page pool: quantize-on-write, fused dequant in attention
-            kc, vc, ksc, vsc = kvcache.paged_ring_write_quant(
-                cache_entry["k"], cache_entry["v"],
-                cache_entry["k_scale"], cache_entry["v_scale"], k, v,
-                page_table, lengths, page_size,
-            )
+            with jax.named_scope("kv_write"):
+                kc, vc, ksc, vsc = kvcache.paged_ring_write_quant(
+                    cache_entry["k"], cache_entry["v"],
+                    cache_entry["k_scale"], cache_entry["v_scale"], k, v,
+                    page_table, lengths, page_size,
+                )
             new_entry["k"], new_entry["v"] = kc, vc
             new_entry["k_scale"], new_entry["v_scale"] = ksc, vsc
-            o = attn.paged_decode_attention(
-                q, kc, vc, page_table, lengths, window=cfg.sliding_window,
-                k_scale=ksc, v_scale=vsc,
-            )
+            with jax.named_scope("attention"):
+                o = attn.paged_decode_attention(
+                    q, kc, vc, page_table, lengths, window=cfg.sliding_window,
+                    k_scale=ksc, v_scale=vsc,
+                )
         elif page_table is not None:
-            kc, vc = kvcache.paged_ring_write(
-                cache_entry["k"], cache_entry["v"], k, v,
-                page_table, lengths, page_size,
-            )
+            with jax.named_scope("kv_write"):
+                kc, vc = kvcache.paged_ring_write(
+                    cache_entry["k"], cache_entry["v"], k, v,
+                    page_table, lengths, page_size,
+                )
             new_entry["k"], new_entry["v"] = kc, vc
-            o = attn.paged_decode_attention(
-                q, kc, vc, page_table, lengths, window=cfg.sliding_window
-            )
+            with jax.named_scope("attention"):
+                o = attn.paged_decode_attention(
+                    q, kc, vc, page_table, lengths, window=cfg.sliding_window
+                )
         else:
-            kc, vc = kvcache.ring_write(
-                cache_entry["k"], cache_entry["v"], k, v, lengths
-            )
+            with jax.named_scope("kv_write"):
+                kc, vc = kvcache.ring_write(
+                    cache_entry["k"], cache_entry["v"], k, v, lengths
+                )
             new_entry["k"], new_entry["v"] = kc, vc
-            key_pos = kvcache.ring_key_positions(lengths, kc.shape[1])
-            o = attn.decode_attention(
-                q, kc, vc, lengths, key_pos, window=cfg.sliding_window
-            )
-        x = x + attn.output_proj(p["attn"], o)
+            with jax.named_scope("attention"):
+                key_pos = kvcache.ring_key_positions(lengths, kc.shape[1])
+                o = attn.decode_attention(
+                    q, kc, vc, lengths, key_pos, window=cfg.sliding_window
+                )
+        with jax.named_scope("attention"):
+            x = x + attn.output_proj(p["attn"], o)
         if spec.cross_attn:
             hx = rms_norm(x, p["norm_x"], cfg.norm_eps)
             qx = jnp.einsum("bsd,dhk->bshk", hx, p["cross"]["wq"].astype(hx.dtype))
@@ -383,7 +393,8 @@ def apply_layer_decode(
                 mp, h, cfg, topo, expert_mask=expert_mask, train=False
             )
         else:
-            y = apply_mlp(p["ffn"], h, cfg.act)
+            with jax.named_scope("mlp"):
+                y = apply_mlp(p["ffn"], h, cfg.act)
         x = x + y
     return x, new_entry, aux
 
@@ -541,29 +552,35 @@ def apply_stack_prefill_chunk(
             p = block_params[f"pos{i}"]
             ce = cache_entry[f"pos{i}"]
             h = rms_norm(bx, p["norm1"], cfg.norm_eps)
-            q, k, v = attn.project_qkv(p["attn"], h, cfg, angles)
+            with jax.named_scope("attention"):
+                q, k, v = attn.project_qkv(p["attn"], h, cfg, angles)
             if "k_scale" in ce:
                 # int8 page pool: quantize-on-write + fused dequant
-                kc, vc, ksc, vsc = kvcache.paged_write_tokens_quant(
-                    ce["k"], ce["v"], ce["k_scale"], ce["v_scale"], k, v,
-                    page_table, positions, valid, page_size,
-                )
-                o = attn.paged_chunk_attention(
-                    q, kc, vc, page_table, positions, last_pos,
-                    window=cfg.sliding_window, k_scale=ksc, v_scale=vsc,
-                )
+                with jax.named_scope("kv_write"):
+                    kc, vc, ksc, vsc = kvcache.paged_write_tokens_quant(
+                        ce["k"], ce["v"], ce["k_scale"], ce["v_scale"], k, v,
+                        page_table, positions, valid, page_size,
+                    )
+                with jax.named_scope("attention"):
+                    o = attn.paged_chunk_attention(
+                        q, kc, vc, page_table, positions, last_pos,
+                        window=cfg.sliding_window, k_scale=ksc, v_scale=vsc,
+                    )
                 entry_out = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
             else:
-                kc, vc = kvcache.paged_write_tokens(
-                    ce["k"], ce["v"], k, v, page_table, positions, valid,
-                    page_size,
-                )
-                o = attn.paged_chunk_attention(
-                    q, kc, vc, page_table, positions, last_pos,
-                    window=cfg.sliding_window,
-                )
+                with jax.named_scope("kv_write"):
+                    kc, vc = kvcache.paged_write_tokens(
+                        ce["k"], ce["v"], k, v, page_table, positions, valid,
+                        page_size,
+                    )
+                with jax.named_scope("attention"):
+                    o = attn.paged_chunk_attention(
+                        q, kc, vc, page_table, positions, last_pos,
+                        window=cfg.sliding_window,
+                    )
                 entry_out = {"k": kc, "v": vc}
-            bx = bx + attn.output_proj(p["attn"], o)
+            with jax.named_scope("attention"):
+                bx = bx + attn.output_proj(p["attn"], o)
             if _has_ffn(spec, cfg):
                 h = rms_norm(bx, p["norm2"], cfg.norm_eps)
                 if spec.moe:
@@ -575,7 +592,8 @@ def apply_stack_prefill_chunk(
                         train=False,
                     )
                 else:
-                    y = apply_mlp(p["ffn"], h, cfg.act)
+                    with jax.named_scope("mlp"):
+                        y = apply_mlp(p["ffn"], h, cfg.act)
                 bx = bx + y
             new_entries[f"pos{i}"] = entry_out
         return bx, new_entries
@@ -616,15 +634,16 @@ def embed_inputs(params, cfg, tokens, patch_embeds=None):
 
 
 def lm_logits(params, cfg, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(x.dtype)
-    logits = x @ head
-    if cfg.logit_softcap > 0:
-        c = cfg.logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    if cfg.padded_vocab_size != cfg.vocab_size:
-        pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
-        logits = jnp.where(pad_mask, logits, -1e30)
-    return logits
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(x.dtype)
+        logits = x @ head
+        if cfg.logit_softcap > 0:
+            c = cfg.logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        if cfg.padded_vocab_size != cfg.vocab_size:
+            pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
+            logits = jnp.where(pad_mask, logits, -1e30)
+        return logits

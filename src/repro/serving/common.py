@@ -56,6 +56,11 @@ class Request:
     # filled by the engine
     generated: List[int] = field(default_factory=list)
     submit_time: float = 0.0
+    # a prefill takes the request's slot, and the prompt's last chunk comes
+    # back from the cloud tier: TTFT = admission wait (admit - submit) +
+    # prefill (prefill_done - admit) + activation (first_token - prefill_done)
+    admit_time: Optional[float] = None
+    prefill_done_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     seq: int = -1  # submission order stamp (ties within a priority class)
@@ -312,6 +317,54 @@ class TraceCounter:
         return self._fn(*args)
 
 
+SPAN_PREFIX = "engine:"
+_SPAN_NAMES: Dict[str, str] = {}  # name -> "engine:" + name, built once
+
+
+def span(name: str, **ids):
+    """A host span ``engine:<name>`` on the profiler's clock, with ``ids``
+    (a request id, a slot, a group) as its arguments.  It records only
+    while a profiler trace is active, so device operations and the host
+    code around them land on one clock; otherwise it records and formats
+    nothing.
+
+    The engines nest them: ``step`` holds one span per phase of the tick
+    (``drain``, ``harvest``, ``prefetch``, ``replan``, ``admit``,
+    ``prefill``, ``resolve``, ``draft``, ``activate``, ``end_stage``), and
+    each phase holds a ``sync`` span wherever the host blocks on the
+    device, so a tick's own host time is ``step`` less its ``sync``
+    spans."""
+    full = _SPAN_NAMES.get(name)
+    if full is None:
+        full = _SPAN_NAMES[name] = SPAN_PREFIX + name
+    return jax.profiler.TraceAnnotation(full, **ids)
+
+
+class _GcSpans:
+    """A ``gc.callbacks`` hook that wraps every collection of the Python
+    garbage collector in an ``engine:gc`` span with its generation, so a
+    long collection inside a tick is named in a trace like any phase."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: Dict):
+        if phase == "start":
+            self._open = span("gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def install_gc_spans():
+    """Add the ``engine:gc`` hook to ``gc.callbacks`` once per process."""
+    import gc
+
+    if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpans())
+
+
 class SlotEngineBase:
     """Slot lifecycle shared by the serving engines.
 
@@ -456,7 +509,11 @@ class SlotEngineBase:
                     break
                 req = queue[0]
                 self.waiting.remove(req)
+                if req.admit_time is None:
+                    req.admit_time = self.clock()
                 tok, payload = self._prefill_into_slot(slot, req)
+                if req.prefill_done_time is None:
+                    req.prefill_done_time = self.clock()
                 req.generated.append(tok)
                 if req.first_token_time is None:
                     req.first_token_time = self.clock()

@@ -130,7 +130,9 @@ from repro.serving.common import (
     TraceCounter,
     VirtualClock,
     element_bytes,
+    install_gc_spans,
     payload_block_until_ready,
+    span,
 )
 from repro.serving.endcloud import (
     TierPlan,
@@ -151,6 +153,38 @@ from repro.serving.specdecode import (
 __all__ = ["EndCloudServingEngine"]
 
 _KEEP = object()  # sentinel: "no pending mask change"
+
+
+def _boundary_codec(codec, compress: bool, quantize: bool, act):
+    """The boundary codec's trace-time steps, each under the ``codec`` name
+    scope: ``encode`` on the end tier (the low-rank encode when one is
+    configured, then the int8 quantization, which makes the payload an
+    ``(codes int8, scale f16)`` tuple — the tuple-aware metering/blocking
+    helpers in ``serving.common`` handle it); ``unwire`` (dequantize) and
+    ``decode`` (the low-rank decode, cast to the activation dtype) on the
+    cloud tier."""
+
+    def encode(x):
+        with jax.named_scope("codec"):
+            z = comp.encode_1d(codec, x) if compress else x
+            return comp.quantize_boundary(z) if quantize else z
+
+    def unwire(z):
+        with jax.named_scope("codec"):
+            return comp.dequantize_boundary(*z, dtype=act) if quantize else z
+
+    def decode(z):
+        with jax.named_scope("codec"):
+            x = comp.decode_1d(codec, z) if compress else z
+            return x.astype(act)
+
+    return encode, unwire, decode
+
+
+def _greedy_ids(logits: jax.Array) -> jax.Array:
+    """Greedy token ids, resolved in-trace under the ``lm_head`` scope."""
+    with jax.named_scope("lm_head"):
+        return jnp.argmax(logits, -1).astype(jnp.int32)
 
 
 def _masks_equal(a, b) -> bool:
@@ -278,6 +312,7 @@ class EndCloudServingEngine(SlotEngineBase):
         self.preempt_spill_bytes = 0
         # a VirtualClock switches request stamps onto the modeled timeline
         self._virtual_time = isinstance(self.clock, VirtualClock)
+        install_gc_spans()
         self.model = model
         self.cfg = model.cfg
         self.params = params
@@ -742,8 +777,9 @@ class EndCloudServingEngine(SlotEngineBase):
         tier's MoE layers by the stack) — they order the eq. 4 group admit
         and the pool's prefetch/evict priorities."""
         n_layers = max(len(self._active_lids()), 1)
-        ef = np.asarray(stats["expert_frac"], np.float64) / n_layers
-        gf = np.asarray(stats["group_frac"], np.float64) / n_layers
+        with span("sync"):
+            ef = np.asarray(stats["expert_frac"], np.float64) / n_layers
+            gf = np.asarray(stats["group_frac"], np.float64) / n_layers
         if not (np.isfinite(ef).all() and np.isfinite(gf).all()):
             return
         d = self._freq_decay
@@ -772,21 +808,13 @@ class EndCloudServingEngine(SlotEngineBase):
         cfg = self.cfg
         topo = self.model.topo
         tiers = self.tiers
-        codec, compress, end_mask = tiers.codec, tiers.compress, tiers.end_mask
-        act = jnp.dtype(cfg.dtype)
+        end_mask = tiers.end_mask
         ps = self.page_size
         pooled = self._expert_pooled
-        qb = self.quantize_boundary
-
-        def wire_encode(z):
-            """Second codec stage: int8-quantize the boundary payload (after
-            the low-rank encode when one is configured).  The payload
-            becomes an ``(codes int8, scale f16)`` tuple — the tuple-aware
-            metering/blocking helpers in ``serving.common`` handle it."""
-            return comp.quantize_boundary(z) if qb else z
-
-        def wire_decode(z):
-            return comp.dequantize_boundary(*z, dtype=act) if qb else z
+        wire_encode, wire_decode, codec_decode = _boundary_codec(
+            tiers.codec, tiers.compress, self.quantize_boundary,
+            jnp.dtype(cfg.dtype),
+        )
 
         def decode_angles(lengths, B):
             pos = lengths[:, None]
@@ -812,7 +840,7 @@ class EndCloudServingEngine(SlotEngineBase):
                 end_params, x, cfg, topo, angles, pages, lengths,
                 expert_mask=end_mask, page_table=table, page_size=ps,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             if self._route_stats_enabled:
                 # dense-mask MoE engines measure routing too: the eq. 4
                 # group priority must come from traffic, not natural order
@@ -835,7 +863,7 @@ class EndCloudServingEngine(SlotEngineBase):
                 expert_mask=emask, page_table=table, page_size=ps,
                 expert_resident=eres,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             stats = {
                 "expert_frac": aux["expert_frac"],
                 "group_frac": aux["group_frac"],
@@ -845,8 +873,7 @@ class EndCloudServingEngine(SlotEngineBase):
         def cloud_logits(cloud_params, z, pages, table, lengths):
             z = wire_decode(z)
             angles = decode_angles(lengths, z.shape[0])
-            x = comp.decode_1d(codec, z) if compress else z
-            x = x.astype(act)
+            x = codec_decode(z)
             x, new_pages, _ = transformer.apply_stack_decode(
                 cloud_params, x, cfg, topo, angles, pages, lengths,
                 expert_mask=None, page_table=table, page_size=ps,
@@ -859,7 +886,7 @@ class EndCloudServingEngine(SlotEngineBase):
             )
             # greedy ids resolved in-trace: one int32 per row crosses to the
             # host (batched per tick) instead of a [B, V] logits row
-            return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
+            return _greedy_ids(logits), new_pages
 
         def end_prefill_chunk(end_params, tokens, pages, table, start, n_valid):
             B, C = tokens.shape
@@ -870,7 +897,7 @@ class EndCloudServingEngine(SlotEngineBase):
                 end_params, x, cfg, topo, angles, pages, table,
                 positions, n_valid, ps, expert_mask=end_mask,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             return z, new_pages
 
         def end_prefill_chunk_pooled(end_params, tokens, pages, table, start,
@@ -884,7 +911,7 @@ class EndCloudServingEngine(SlotEngineBase):
                 positions, n_valid, ps, expert_mask=emask,
                 expert_resident=eres,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             return z, new_pages
 
         def cloud_prefill_hidden(cloud_params, z, pages, table, start,
@@ -893,8 +920,7 @@ class EndCloudServingEngine(SlotEngineBase):
             B, C = z.shape[:2]
             positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
             angles = chunk_angles(positions)
-            x = comp.decode_1d(codec, z) if compress else z
-            x = x.astype(act)
+            x = codec_decode(z)
             return transformer.apply_stack_prefill_chunk(
                 cloud_params, x, cfg, topo, angles, pages, table,
                 positions, n_valid, ps, expert_mask=None,
@@ -914,7 +940,7 @@ class EndCloudServingEngine(SlotEngineBase):
             B = x.shape[0]
             x_last = x[jnp.arange(B), jnp.maximum(n_valid - 1, 0)][:, None]
             logits = transformer.lm_logits(cloud_params, cfg, x_last)[:, 0]
-            return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
+            return _greedy_ids(logits), new_pages
 
         self._build_gen += 1
         gen = self._build_gen
@@ -1078,16 +1104,12 @@ class EndCloudServingEngine(SlotEngineBase):
         cfg = self.cfg
         topo = self.model.topo
         tiers = self.tiers
-        codec, compress, end_mask = tiers.codec, tiers.compress, tiers.end_mask
-        act = jnp.dtype(cfg.dtype)
+        end_mask = tiers.end_mask
         ps = self.page_size
-        qb = self.quantize_boundary
-
-        def wire_encode(z):
-            return comp.quantize_boundary(z) if qb else z
-
-        def wire_decode(z):
-            return comp.dequantize_boundary(*z, dtype=act) if qb else z
+        wire_encode, wire_decode, codec_decode = _boundary_codec(
+            tiers.codec, tiers.compress, self.quantize_boundary,
+            jnp.dtype(cfg.dtype),
+        )
 
         def decode_angles(lengths, B):
             pos = lengths[:, None]
@@ -1123,7 +1145,7 @@ class EndCloudServingEngine(SlotEngineBase):
                     expert_mask=emask,
                 )
                 logits = transformer.lm_logits(params, cfg, x)[:, 0]
-                tokens = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+                tokens = _greedy_ids(logits)[:, None]
                 drafts.append(tokens[:, 0])
                 lengths = lengths + 1
             return jnp.stack(drafts, axis=1), blocks
@@ -1136,7 +1158,7 @@ class EndCloudServingEngine(SlotEngineBase):
                 end_params, x, cfg, topo, angles, pages, table,
                 positions, n_valid, ps, expert_mask=end_mask,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             return z, new_pages
 
         def spec_end_pooled(end_params, tokens, pages, table, start, n_valid,
@@ -1149,15 +1171,14 @@ class EndCloudServingEngine(SlotEngineBase):
                 positions, n_valid, ps, expert_mask=emask,
                 expert_resident=eres,
             )
-            z = wire_encode(comp.encode_1d(codec, x) if compress else x)
+            z = wire_encode(x)
             return z, new_pages
 
         def spec_cloud(cloud_params, z, pages, table, start, n_valid):
             z = wire_decode(z)
             positions = start[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
             angles = chunk_angles(positions)
-            x = comp.decode_1d(codec, z) if compress else z
-            x = x.astype(act)
+            x = codec_decode(z)
             x, new_pages = transformer.apply_stack_prefill_chunk(
                 cloud_params, x, cfg, topo, angles, pages, table,
                 positions, n_valid, ps, expert_mask=None,
@@ -1165,7 +1186,7 @@ class EndCloudServingEngine(SlotEngineBase):
             # per-position greedy ids, resolved in-trace: k int32 per row
             # cross back down the link, never the [B, k, V] logits
             logits = transformer.lm_logits(cloud_params, cfg, x)
-            return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
+            return _greedy_ids(logits), new_pages
 
         gen = self._build_gen
 
@@ -1241,7 +1262,8 @@ class EndCloudServingEngine(SlotEngineBase):
         blocks = self._draft_prefill_fn()(
             self.params, jnp.asarray(padded)[None], self._spec_emask()
         )
-        jax.block_until_ready(blocks)
+        with span("sync"):
+            jax.block_until_ready(blocks)
         td = self._draft_seconds(L)
         if td is None:
             td = time.perf_counter() - t0
@@ -1327,7 +1349,8 @@ class EndCloudServingEngine(SlotEngineBase):
         drafts_dev, dcache = draft_fn(
             self.params, tokens, dcache, dlens, self._spec_emask()
         )
-        jax.block_until_ready(drafts_dev)
+        with span("sync"):
+            jax.block_until_ready(drafts_dev)
         td = self._draft_seconds(gsz * k)
         if td is None:
             td = time.perf_counter() - t0
@@ -1365,7 +1388,8 @@ class EndCloudServingEngine(SlotEngineBase):
             self.end_params, tok_chunk, self._end_pages, table, start,
             nv_dev, *eargs,
         )
-        payload_block_until_ready(z)
+        with span("sync"):
+            payload_block_until_ready(z)
         te = self._stage_seconds("end", gsz * k)
         if te is None:
             te = time.perf_counter() - t1
@@ -1426,7 +1450,8 @@ class EndCloudServingEngine(SlotEngineBase):
         ids_dev, self._cloud_pages = cloud_fn(
             self.cloud_params, z, self._cloud_pages, table, start, nv
         )
-        ids_dev.block_until_ready()
+        with span("sync"):
+            ids_dev.block_until_ready()
         tc = self._stage_seconds("cloud", (ge - gs) * k)
         if tc is None:
             tc = time.perf_counter() - t0
@@ -1594,6 +1619,12 @@ class EndCloudServingEngine(SlotEngineBase):
                 if self._virtual_time:
                     # prefill cannot start before the request arrived
                     job.ready_s = req.submit_time
+                if req.admit_time is None:
+                    # the modeled schedule books the prefill from its ready
+                    # time, so on the virtual clock admission is that time
+                    req.admit_time = (
+                        job.ready_s if self._virtual_time else self.clock()
+                    )
                 self._jobs[slot] = job
             admitted += 1
         return admitted
@@ -1812,7 +1843,8 @@ class EndCloudServingEngine(SlotEngineBase):
             self.end_params, tokens, self._end_pages,
             self.end_pool.device_rows([slot]), start, valid, *eargs,
         )
-        payload_block_until_ready(z)
+        with span("sync"):
+            payload_block_until_ready(z)
         te = self._stage_seconds("end", v)
         if te is None:
             te = time.perf_counter() - t0
@@ -1830,7 +1862,8 @@ class EndCloudServingEngine(SlotEngineBase):
             self.cloud_params, z, self._cloud_pages,
             self.cloud_pool.device_rows([self._cslot(slot)]), start, valid,
         )
-        ids.block_until_ready()
+        with span("sync"):
+            ids.block_until_ready()
         tc = self._stage_seconds("cloud", v)
         if tc is None:
             tc = time.perf_counter() - t1
@@ -1846,6 +1879,12 @@ class EndCloudServingEngine(SlotEngineBase):
 
         job.pos += v
         if job.pos >= S:
+            if req.prefill_done_time is None:
+                # the last chunk's cloud call has returned; on the modeled
+                # axis the prompt is done when that chunk drains the cloud
+                req.prefill_done_time = (
+                    done_c if self._virtual_time else self.clock()
+                )
             # stash the DEVICE scalar; the tick's single batched
             # device->host transfer resolves it (_resolve_prefill_tokens)
             job.first_tok_dev = ids[0]
@@ -1863,7 +1902,8 @@ class EndCloudServingEngine(SlotEngineBase):
         ]
         if not pend:
             return
-        host = jax.device_get([job.first_tok_dev for _, job in pend])
+        with span("sync"):
+            host = jax.device_get([job.first_tok_dev for _, job in pend])
         self.n_host_syncs += 1
         for (_slot, job), tok in zip(pend, host):
             job.first_tok = int(tok)
@@ -2031,7 +2071,8 @@ class EndCloudServingEngine(SlotEngineBase):
                 self.end_params, tokens, self._end_pages, table, lengths
             )
             stats = None
-        payload_block_until_ready(z)
+        with span("sync"):
+            payload_block_until_ready(z)
         te = self._stage_seconds("end", ge - gs)
         if te is None:
             te = time.perf_counter() - t0
@@ -2083,7 +2124,8 @@ class EndCloudServingEngine(SlotEngineBase):
         ids_dev, self._cloud_pages = self._cloud_step(
             self.cloud_params, z, self._cloud_pages, table, lengths
         )
-        ids_dev.block_until_ready()
+        with span("sync"):
+            ids_dev.block_until_ready()
         tc = self._stage_seconds("cloud", ge - gs)
         if tc is None:
             tc = time.perf_counter() - t0
@@ -2112,7 +2154,8 @@ class EndCloudServingEngine(SlotEngineBase):
         speculative rounds, the draft tokens), then per-group commit in
         drain order — plain groups harvest directly, speculative groups go
         through accept/rollback (:meth:`_spec_commit`)."""
-        host = jax.device_get([rec["dev"] for rec in records])
+        with span("sync"):
+            host = jax.device_get([rec["dev"] for rec in records])
         self.n_host_syncs += 1
         emitted = 0
         for rec, dev in zip(records, host):
@@ -2143,29 +2186,39 @@ class EndCloudServingEngine(SlotEngineBase):
         end-step and a long prompt's prefill never stalls other groups'
         decode."""
         emitted = 0
-        if self.link_degraded:
-            self.degraded_ticks += 1
-        drained = [
-            self._drain_cloud_stage(g)
-            for g in range(self.n_groups)
-            if self._phase[g] == "boundary"
-        ]
-        if drained:
-            emitted += self._harvest_drained(drained)
-        self._advance_expert_prefetch()
-        self._apply_pending_replan()
-        self._admit()
-        for slot in sorted(self._jobs):
-            job = self._jobs[slot]
-            if job.first_tok is None and job.first_tok_dev is None:
-                self._advance_prefill(job)
-        self._resolve_prefill_tokens()
-        if self._spec_plan_k > 1:
-            self._spec_refresh_drafts()
-        self._activate_ready_jobs()
-        for g in range(self.n_groups):
-            if self._phase[g] == "ready" and self._group_active(g):
-                self._run_end_stage(g)
+        with span("step"):
+            if self.link_degraded:
+                self.degraded_ticks += 1
+            drained = []
+            for g in range(self.n_groups):
+                if self._phase[g] == "boundary":
+                    with span("drain", group=g):
+                        drained.append(self._drain_cloud_stage(g))
+            if drained:
+                with span("harvest"):
+                    emitted += self._harvest_drained(drained)
+            with span("prefetch"):
+                self._advance_expert_prefetch()
+            with span("replan"):
+                self._apply_pending_replan()
+            with span("admit"):
+                self._admit()
+            for slot in sorted(self._jobs):
+                job = self._jobs[slot]
+                if job.first_tok is None and job.first_tok_dev is None:
+                    with span("prefill", req=job.req.request_id, slot=slot):
+                        self._advance_prefill(job)
+            with span("resolve"):
+                self._resolve_prefill_tokens()
+            if self._spec_plan_k > 1:
+                with span("draft"):
+                    self._spec_refresh_drafts()
+            with span("activate"):
+                self._activate_ready_jobs()
+            for g in range(self.n_groups):
+                if self._phase[g] == "ready" and self._group_active(g):
+                    with span("end_stage", group=g):
+                        self._run_end_stage(g)
         return emitted
 
     # -- dynamic replanning ---------------------------------------------------
