@@ -1,12 +1,14 @@
 """Oracle: gather-free paged decode/chunk attention in pure JAX.
 
 The parity target for the Pallas kernel in ``kernel.py`` — and the
-implementation the models layer dispatches to off-TPU.  Instead of
-materializing a dense ``[B, pps*ps, KV, hd]`` ring view of the page pool
+implementation the models layer dispatches to off-TPU.  It takes the same
+operands: a tier's pools stacked over its blocks, ``[R, P+1, ps, KV*hd]``,
+and the block to attend.  Instead of materializing a dense
+``[B, pps*ps, KV, hd]`` ring view of the page pool
 (``kvcache.paged_gather``) and sweeping all of it, the softmax loop scans
-the page *table*: each step indexes ``pool[table[:, e]]`` — one physical
-page per slot — masks the page's ring positions against the queries, and
-folds it into an online-softmax accumulator.  KV traffic per step is one
+the page *table*: each step indexes ``pool[layer, table[:, e]]`` — one
+physical page per slot — masks the page's ring positions against the
+queries, and folds it into an online-softmax accumulator.  KV traffic per step is one
 page per (slot, entry) instead of the whole ring, and nothing is ever
 written back to HBM between the pool and the output.
 
@@ -42,22 +44,23 @@ NEG_INF = -1e30
 
 def paged_attention_ref(
     q: jax.Array,  # [B, C, H, hd] (C=1 for decode)
-    pool_k: jax.Array,  # [P+1, ps, KV, hd] (row P = garbage page)
+    pool_k: jax.Array,  # [R, P+1, ps, KV*hd] (row P = garbage page)
     pool_v: jax.Array,
+    layer: jax.Array,  # int32 scalar: the block of the stack to attend
     table: jax.Array,  # [B, pps] int32 physical page per ring entry
     q_positions: jax.Array,  # [B, C] int32 absolute position of each query
     lengths: jax.Array,  # [B] int32 ring anchor (position of the last write)
     *,
     window: Optional[int] = None,
-    k_scale: Optional[jax.Array] = None,  # [P+1, ps] f16 per-token sidecar
+    k_scale: Optional[jax.Array] = None,  # [R, P+1, ps] f16 per-token sidecar
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
     B, C, H, hd = q.shape
-    ps, KV = pool_k.shape[1], pool_k.shape[2]
+    ps, KV = pool_k.shape[2], pool_k.shape[3] // hd
     pps = table.shape[1]
     W = pps * ps
     G = H // KV
-    garbage = pool_k.shape[0] - 1
+    garbage = pool_k.shape[1] - 1
     scale = 1.0 / (hd ** 0.5)
     qr = q.reshape(B, C, KV, G, hd)
     ln = lengths[:, None].astype(jnp.int32)  # [B, 1]
@@ -66,16 +69,16 @@ def paged_attention_ref(
     def page_step(carry, e):
         m, l, acc = carry
         phys = table[:, e]  # [B]
-        k_page = pool_k[phys]  # [B, ps, KV, hd]
-        v_page = pool_v[phys]
+        k_page = pool_k[layer, phys].reshape(B, ps, KV, hd)
+        v_page = pool_v[layer, phys].reshape(B, ps, KV, hd)
         if k_scale is not None:
             # quantized pool: dequantize the gathered page in registers —
             # one f16 scale per token row, shared across heads and head dim
             k_page = k_page.astype(jnp.float32) * (
-                k_scale[phys].astype(jnp.float32)[:, :, None, None]
+                k_scale[layer, phys].astype(jnp.float32)[:, :, None, None]
             )
             v_page = v_page.astype(jnp.float32) * (
-                v_scale[phys].astype(jnp.float32)[:, :, None, None]
+                v_scale[layer, phys].astype(jnp.float32)[:, :, None, None]
             )
         slot = e * ps + jnp.arange(ps, dtype=jnp.int32)[None, :]  # [1, ps]
         kp = ln - jnp.mod(ln - slot, W)  # [B, ps]

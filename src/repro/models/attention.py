@@ -420,20 +420,22 @@ def set_paged_attention_impl(impl: Optional[str]):
 
 def paged_chunk_attention(
     q: jax.Array,  # [B, C, H, hd] C queries (decode is the C=1 case)
-    pool_k: jax.Array,  # [P+1, ps, KV, hd] page pool (row P = garbage)
+    pool_k: jax.Array,  # [R, P+1, ps, KV*hd] pools of R blocks (row P = garbage)
     pool_v: jax.Array,
+    layer: jax.Array,  # int32 scalar: the block of the stack to attend
     table: jax.Array,  # [B, pps] int32 physical page per ring entry
     q_positions: jax.Array,  # [B, C] int32 absolute position of each query
     lengths: jax.Array,  # [B] int32 ring anchor (last written position)
     *,
     window: Optional[int] = None,
-    k_scale: Optional[jax.Array] = None,  # [P+1, ps] f16 sidecar (int8 pool)
+    k_scale: Optional[jax.Array] = None,  # [R, P+1, ps] f16 sidecar (int8 pool)
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention straight off the page pool: page-table lookup, ring-position
     masking (``kvcache.ring_key_positions`` semantics), and online-softmax
-    attention fused into one sweep over the slot's *mapped* pages — no dense
-    ``paged_gather`` ring view is ever materialized.  Numerically the masked
+    attention fused into one sweep over the slot's *mapped* pages of block
+    ``layer`` — no dense ``paged_gather`` ring view is ever materialized, and
+    the stack is read where it lies, never sliced per layer.  Numerically the masked
     softmax of :func:`chunk_attention` over the gathered ring (exact in the
     score set; online-softmax reassociation only), which survives as the
     test oracle.  With ``k_scale``/``v_scale`` the pools hold int8 codes and
@@ -446,14 +448,14 @@ def paged_chunk_attention(
         from repro.kernels.paged_attention.ops import paged_attention
 
         return paged_attention(
-            q, pool_k, pool_v, table, q_positions, lengths, window=window,
-            k_scale=k_scale, v_scale=v_scale,
+            q, pool_k, pool_v, layer, table, q_positions, lengths,
+            window=window, k_scale=k_scale, v_scale=v_scale,
         )
     from repro.kernels.paged_attention.ref import paged_attention_ref
 
     return paged_attention_ref(
-        q, pool_k, pool_v, table, q_positions, lengths, window=window,
-        k_scale=k_scale, v_scale=v_scale,
+        q, pool_k, pool_v, layer, table, q_positions, lengths,
+        window=window, k_scale=k_scale, v_scale=v_scale,
     )
 
 
@@ -461,6 +463,7 @@ def paged_decode_attention(
     q: jax.Array,  # [B, 1, H, hd]
     pool_k: jax.Array,
     pool_v: jax.Array,
+    layer: jax.Array,
     table: jax.Array,
     lengths: jax.Array,  # [B] int32 position of the current (just-written) token
     *,
@@ -472,8 +475,8 @@ def paged_decode_attention(
     :func:`paged_chunk_attention` (the query sits at ``lengths``, which is
     also the ring anchor)."""
     return paged_chunk_attention(
-        q, pool_k, pool_v, table, lengths[:, None], lengths, window=window,
-        k_scale=k_scale, v_scale=v_scale,
+        q, pool_k, pool_v, layer, table, lengths[:, None], lengths,
+        window=window, k_scale=k_scale, v_scale=v_scale,
     )
 
 

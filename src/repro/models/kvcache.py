@@ -9,9 +9,10 @@ exactly like sliding-window attention with window W once it wraps.
 
 **Paged caches** (what the serving engines allocate): instead of a dense
 ``[R, B, W, KV, hd]`` ring per slot, a tier owns one shared :class:`PagePool`
-of ``num_pages`` fixed-size pages — leaves are ``[R, P+1, page_size, KV,
-hd]`` (the extra last row is the *garbage page* that absorbs writes routed
-away from unmapped or inactive slots) — plus a per-slot *page table*
+of ``num_pages`` fixed-size pages — leaves are ``[R, P+1, page_size,
+KV*hd]`` (the extra last row is the *garbage page* that absorbs writes
+routed away from unmapped or inactive slots; a token's heads lie side by
+side in one lane-dense row) — plus a per-slot *page table*
 ``[pages_per_slot]`` of physical page indices.  Ring semantics are
 preserved at page granularity: position ``p`` lives at table entry
 ``(p // page_size) % pages_per_slot``, offset ``p % page_size``, so the
@@ -23,7 +24,12 @@ bounded page list; ring wrap reuses the slot's own pages in place.
 The pool's allocator is host-side (NumPy bookkeeping between engine ticks);
 the jitted stage functions take the device page table as a runtime argument,
 so compiled traces depend only on chunk/group *shapes*, never on prompt
-lengths or allocation state.
+lengths or allocation state.  The stage functions take the whole stacked
+storage and write it in place: each layer scatters its tokens at
+``[block, page, offset]`` and attention reads block ``block`` of the stack
+directly, so no layer of a pool is sliced out or copied back.  The serving
+engines do not donate the storage, so each stage program copies every pool
+leaf once on entry and updates that copy.
 
 ``lengths`` is per-slot (continuous batching: every request in the batch has
 its own offset).
@@ -217,7 +223,7 @@ class PagePool:
     """Host-side page allocator for one tier's shared KV page pool.
 
     Physical pages ``0..num_pages-1`` index the second axis of the tier's
-    storage leaves (``[R, num_pages+1, page_size, KV, hd]``; row
+    storage leaves (``[R, num_pages+1, page_size, KV*hd]``; row
     ``num_pages`` is the garbage page and is never allocated).  Per-slot
     page tables map ring entries to physical pages; ``-1`` = unmapped.
 
@@ -461,8 +467,11 @@ def init_paged_blocks(cfg, n_blocks: int, num_pages: int, page_size: int,
                       dtype=jnp.bfloat16, *, quantized: bool = False) -> Dict:
     """Paged KV storage for ``n_blocks`` stacked block repeats of an
     attention-only pattern: per position, ``k``/``v`` leaves shaped
-    ``[n_blocks, num_pages + 1, page_size, KV, hd]`` (last row = garbage
-    page).
+    ``[n_blocks, num_pages + 1, page_size, KV*hd]`` (last row = garbage
+    page).  A token's KV heads lie side by side in one row: ``KV*hd``
+    lanes tile the TPU's (8|16, 128) layout where ``hd`` alone may not
+    (switch-base: 12 x 64 = 768), so the device keeps the stack row-major,
+    the layout the paged-attention kernel reads, and no call transposes it.
 
     With ``quantized=True`` the k/v leaves store int8 codes and each
     position additionally carries ``k_scale``/``v_scale`` sidecar leaves
@@ -472,14 +481,14 @@ def init_paged_blocks(cfg, n_blocks: int, num_pages: int, page_size: int,
     re-splits move them with their pages for free.
     """
     assert pattern_is_pageable(cfg), "paged storage needs an attn-only pattern"
-    KV, hd = cfg.num_kv_heads, cfg.head_dim
+    row = cfg.num_kv_heads * cfg.head_dim
     if quantized:
         dtype = jnp.int8
     blocks: Dict[str, Dict] = {}
     for i, _spec in enumerate(cfg.layer_pattern):
         entry = {
-            "k": jnp.zeros((n_blocks, num_pages + 1, page_size, KV, hd), dtype),
-            "v": jnp.zeros((n_blocks, num_pages + 1, page_size, KV, hd), dtype),
+            "k": jnp.zeros((n_blocks, num_pages + 1, page_size, row), dtype),
+            "v": jnp.zeros((n_blocks, num_pages + 1, page_size, row), dtype),
         }
         if quantized:
             entry["k_scale"] = jnp.zeros(
@@ -496,11 +505,7 @@ def paged_block_bytes(blocks: Dict) -> int:
     """Bytes one physical page occupies across all of a tier's block leaves
     (the unit ``kv_bytes_*`` metrics are denominated in).  Scale sidecars
     count toward their page, so quantized pools meter honestly."""
-    total = 0
-    for leaf in jax.tree.leaves(blocks):
-        if leaf.ndim >= 2 and leaf.shape[0] > 0:
-            total += leaf[:, 0].nbytes
-    return total
+    return sum(leaf.nbytes // leaf.shape[1] for leaf in jax.tree.leaves(blocks))
 
 
 def dense_page_bytes(cfg, n_blocks: int, page_size: int, dtype=None) -> int:
@@ -534,19 +539,18 @@ def quantize_kv_tokens(x: jax.Array):
 
 def dequantize_kv_pool(pool: jax.Array, scale: jax.Array,
                        dtype=jnp.bfloat16) -> jax.Array:
-    """``pool [..., ps, KV, hd] int8 + scale [..., ps] -> dense-equivalent
+    """``pool [..., ps, KV*hd] int8 + scale [..., ps] -> dense-equivalent
     pool`` (test oracle; the serving consumers dequantize in VMEM)."""
     return (
-        pool.astype(jnp.float32)
-        * scale.astype(jnp.float32)[..., None, None]
+        pool.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]
     ).astype(dtype)
 
 
 # -- device-side paged reads/writes (pure; used inside jitted stage fns) -----
 
 
-def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:
-    """pool [P+1, ps, KV, hd], table [B, pps] -> dense ring view
+def paged_gather(pool: jax.Array, table: jax.Array, kv_heads: int) -> jax.Array:
+    """One block's pool [P+1, ps, KV*hd], table [B, pps] -> dense ring view
     [B, pps*ps, KV, hd].  Garbage-routed entries gather junk that the ring
     position mask (``ring_key_positions`` validity) discards.
 
@@ -556,79 +560,85 @@ def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:
     materialized O(B x max_len) copy exists so parity tests can rebuild the
     exact dense ring the fused path must reproduce."""
     B, pps = table.shape
-    buf = pool[table]  # [B, pps, ps, KV, hd]
-    return buf.reshape(B, pps * pool.shape[1], *pool.shape[2:])
+    buf = pool[table]  # [B, pps, ps, KV*hd]
+    return buf.reshape(B, pps * pool.shape[1], kv_heads, -1)
+
+
+def _rows(table: jax.Array, positions: jax.Array, page_size: int):
+    """(physical page, offset) of ring positions ``positions`` [B, ...]
+    through the slots' page tables [B, pps]."""
+    B, pps = table.shape
+    entry = jnp.mod(positions // page_size, pps).reshape(B, -1)
+    phys = jnp.take_along_axis(table, entry, axis=1).reshape(positions.shape)
+    return phys, jnp.mod(positions, page_size)
 
 
 def paged_ring_write(pool_k: jax.Array, pool_v: jax.Array, k, v,
-                     table: jax.Array, lengths: jax.Array, page_size: int):
+                     table: jax.Array, lengths: jax.Array, page_size: int,
+                     layer):
     """Write one new token's k/v ([B, 1, KV, hd]) at ring position
-    ``lengths`` through the page table (paged analogue of
-    :func:`ring_write`)."""
-    pps = table.shape[1]
-    entry = jnp.mod(lengths // page_size, pps)
-    phys = jnp.take_along_axis(table, entry[:, None], axis=1)[:, 0]
-    off = jnp.mod(lengths, page_size)
-    pool_k = pool_k.at[phys, off].set(k[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, off].set(v[:, 0].astype(pool_v.dtype))
+    ``lengths`` through the page table into block ``layer`` of the stacked
+    pools (paged analogue of :func:`ring_write`): a scatter of B rows,
+    which XLA does in place."""
+    phys, off = _rows(table, lengths, page_size)
+    B = k.shape[0]
+    pool_k = pool_k.at[layer, phys, off].set(
+        k[:, 0].reshape(B, -1).astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, phys, off].set(
+        v[:, 0].reshape(B, -1).astype(pool_v.dtype))
     return pool_k, pool_v
 
 
 def paged_ring_write_quant(pool_k, pool_v, pool_ks, pool_vs, k, v,
                            table: jax.Array, lengths: jax.Array,
-                           page_size: int):
+                           page_size: int, layer):
     """Quantize-on-write variant of :func:`paged_ring_write`: the token's
     k/v are int8-quantized with one per-token scale each (shared across KV
     heads and head dim) and both the codes and the f16 scale sidecars are
-    scattered through the page table."""
-    pps = table.shape[1]
-    entry = jnp.mod(lengths // page_size, pps)
-    phys = jnp.take_along_axis(table, entry[:, None], axis=1)[:, 0]
-    off = jnp.mod(lengths, page_size)
+    scattered through the page table into block ``layer``."""
+    phys, off = _rows(table, lengths, page_size)
+    B = k.shape[0]
     qk, sk = quantize_kv_tokens(k[:, 0])
     qv, sv = quantize_kv_tokens(v[:, 0])
-    pool_k = pool_k.at[phys, off].set(qk)
-    pool_v = pool_v.at[phys, off].set(qv)
-    pool_ks = pool_ks.at[phys, off].set(sk)
-    pool_vs = pool_vs.at[phys, off].set(sv)
+    pool_k = pool_k.at[layer, phys, off].set(qk.reshape(B, -1))
+    pool_v = pool_v.at[layer, phys, off].set(qv.reshape(B, -1))
+    pool_ks = pool_ks.at[layer, phys, off].set(sk)
+    pool_vs = pool_vs.at[layer, phys, off].set(sv)
     return pool_k, pool_v, pool_ks, pool_vs
 
 
 def paged_write_tokens(pool_k: jax.Array, pool_v: jax.Array, k, v,
                        table: jax.Array, positions: jax.Array,
-                       valid: jax.Array, page_size: int):
+                       valid: jax.Array, page_size: int, layer):
     """Write a chunk of tokens ([B, C, KV, hd]) at ``positions`` [B, C]
-    through the page table; rows where ``valid`` [B, C] is False (prompt
-    padding) are routed to the garbage page."""
-    pps = table.shape[1]
-    garbage = pool_k.shape[0] - 1
-    entry = jnp.mod(positions // page_size, pps)
-    phys = jnp.take_along_axis(table, entry, axis=1)
-    phys = jnp.where(valid, phys, garbage)
-    off = jnp.mod(positions, page_size)
-    pool_k = pool_k.at[phys, off].set(k.astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, off].set(v.astype(pool_v.dtype))
+    through the page table into block ``layer`` of the stacked pools; rows
+    where ``valid`` [B, C] is False (prompt padding) are routed to the
+    garbage page."""
+    phys, off = _rows(table, positions, page_size)
+    phys = jnp.where(valid, phys, pool_k.shape[1] - 1)
+    B, C = positions.shape
+    pool_k = pool_k.at[layer, phys, off].set(
+        k.reshape(B, C, -1).astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, phys, off].set(
+        v.reshape(B, C, -1).astype(pool_v.dtype))
     return pool_k, pool_v
 
 
 def paged_write_tokens_quant(pool_k, pool_v, pool_ks, pool_vs, k, v,
                              table: jax.Array, positions: jax.Array,
-                             valid: jax.Array, page_size: int):
+                             valid: jax.Array, page_size: int, layer):
     """Quantize-on-write variant of :func:`paged_write_tokens` (chunked
     prefill): per-token int8 codes plus f16 scale sidecars, padding rows
     routed to the garbage page exactly like the dense-dtype path."""
-    pps = table.shape[1]
-    garbage = pool_k.shape[0] - 1
-    entry = jnp.mod(positions // page_size, pps)
-    phys = jnp.take_along_axis(table, entry, axis=1)
-    phys = jnp.where(valid, phys, garbage)
-    off = jnp.mod(positions, page_size)
+    phys, off = _rows(table, positions, page_size)
+    phys = jnp.where(valid, phys, pool_k.shape[1] - 1)
+    B, C = positions.shape
     qk, sk = quantize_kv_tokens(k)
     qv, sv = quantize_kv_tokens(v)
-    pool_k = pool_k.at[phys, off].set(qk)
-    pool_v = pool_v.at[phys, off].set(qv)
-    pool_ks = pool_ks.at[phys, off].set(sk)
-    pool_vs = pool_vs.at[phys, off].set(sv)
+    pool_k = pool_k.at[layer, phys, off].set(qk.reshape(B, C, -1))
+    pool_v = pool_v.at[layer, phys, off].set(qv.reshape(B, C, -1))
+    pool_ks = pool_ks.at[layer, phys, off].set(sk)
+    pool_vs = pool_vs.at[layer, phys, off].set(sv)
     return pool_k, pool_v, pool_ks, pool_vs
 
 

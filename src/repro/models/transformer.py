@@ -2,8 +2,12 @@
 
 A model is ``block_repeat`` copies of ``cfg.layer_pattern`` lowered with a
 single ``jax.lax.scan`` over stacked block parameters (HLO stays small at 94
-layers and 512 devices).  Heterogeneous caches (attention ring buffers, SSM
-states) ride along as scan xs/ys.
+layers and 512 devices).  Heterogeneous dense caches (attention ring
+buffers, SSM states) ride along as scan xs/ys.  Paged KV storage rides the
+scan's carry instead, whole, with the block index: each layer writes its
+tokens at ``[block, page, offset]`` and attention reads block ``block`` of
+the stack, so the loop updates the pools in place and never slices a layer
+out of them or stacks one back.
 """
 
 from __future__ import annotations
@@ -304,12 +308,14 @@ def apply_layer_decode(
     page_table: Optional[jax.Array] = None,  # [B, pps] -> paged KV layout
     page_size: int = 0,
     expert_resident: Optional[Dict] = None,  # this layer's resident tables
+    layer: Optional[jax.Array] = None,  # block index into the paged stack
 ):
     """Single-token decode layer.  Returns (x, new_cache_entry, aux).
 
-    With ``page_table`` set, attention ``k``/``v`` leaves are page pools
-    ``[P+1, page_size, KV, hd]``: the write scatters through the table and
-    attention reads the slot's mapped pages *directly from the pool*
+    With ``page_table`` set, attention ``k``/``v`` leaves are the stacked
+    page pools of every block, ``[R, P+1, page_size, KV*hd]``: the write
+    scatters the token into block ``layer`` through the table and attention
+    reads the slot's mapped pages of that block *directly from the stack*
     (``attn.paged_decode_attention`` — page lookup, ring masking, and
     online softmax fused; no dense ring view is materialized), so HBM
     traffic scales with mapped pages while greedy decode stays
@@ -326,25 +332,26 @@ def apply_layer_decode(
                 kc, vc, ksc, vsc = kvcache.paged_ring_write_quant(
                     cache_entry["k"], cache_entry["v"],
                     cache_entry["k_scale"], cache_entry["v_scale"], k, v,
-                    page_table, lengths, page_size,
+                    page_table, lengths, page_size, layer,
                 )
             new_entry["k"], new_entry["v"] = kc, vc
             new_entry["k_scale"], new_entry["v_scale"] = ksc, vsc
             with jax.named_scope("attention"):
                 o = attn.paged_decode_attention(
-                    q, kc, vc, page_table, lengths, window=cfg.sliding_window,
-                    k_scale=ksc, v_scale=vsc,
+                    q, kc, vc, layer, page_table, lengths,
+                    window=cfg.sliding_window, k_scale=ksc, v_scale=vsc,
                 )
         elif page_table is not None:
             with jax.named_scope("kv_write"):
                 kc, vc = kvcache.paged_ring_write(
                     cache_entry["k"], cache_entry["v"], k, v,
-                    page_table, lengths, page_size,
+                    page_table, lengths, page_size, layer,
                 )
             new_entry["k"], new_entry["v"] = kc, vc
             with jax.named_scope("attention"):
                 o = attn.paged_decode_attention(
-                    q, kc, vc, page_table, lengths, window=cfg.sliding_window
+                    q, kc, vc, layer, page_table, lengths,
+                    window=cfg.sliding_window,
                 )
         else:
             with jax.named_scope("kv_write"):
@@ -454,6 +461,48 @@ def apply_stack_full(
     return x, aux, (cache_stack if collect_cache else None)
 
 
+def _scan_paged(block_fn, x, pages, xs):
+    """``lax.scan`` of ``block_fn`` over the blocks in ``xs`` with the
+    paged storage and the block index in the carry.  Returns
+    (x, pages, ys).
+
+    A tier may hold no blocks (a split at 0 or at R).  The scan would still
+    trace its body, which cannot index a zero-length stack, so the body is
+    then only evaluated for the shapes of what it stacks, on a one-block
+    stand-in, and those come back empty."""
+    if jax.tree.leaves(xs)[0].shape[0]:
+        (x, pages, _), ys = jax.lax.scan(
+            block_fn, (x, pages, jnp.int32(0)), xs
+        )
+        return x, pages, ys
+    sds = jax.ShapeDtypeStruct
+    one = jax.tree.map(lambda a: sds((1,) + a.shape[1:], a.dtype), pages)
+    xs1 = jax.tree.map(lambda a: sds(a.shape[1:], a.dtype), xs)
+    _, ys = jax.eval_shape(block_fn, (x, one, jnp.int32(0)), xs1)
+    return x, pages, jax.tree.map(lambda a: jnp.zeros((0,) + a.shape, a.dtype), ys)
+
+
+def _decode_block(block_params, bx, entries, tab, store, cfg, topo, angles,
+                  lengths, expert_mask, page_table, page_size, layer):
+    """One block repeat of single-token decode: every pattern position's
+    layer in turn.  Returns (x, new_entries, aux sums)."""
+    new_entries = {}
+    aux_acc: Dict[str, jax.Array] = {}
+    for i, spec in enumerate(cfg.layer_pattern):
+        res = None
+        if tab is not None and spec.moe:
+            res = {**tab[f"pos{i}"], "store": store}
+        bx, ne, aux = apply_layer_decode(
+            block_params[f"pos{i}"], bx, spec, cfg, topo, angles,
+            entries[f"pos{i}"], lengths, expert_mask=expert_mask,
+            page_table=page_table, page_size=page_size,
+            expert_resident=res, layer=layer,
+        )
+        new_entries[f"pos{i}"] = ne
+        aux_acc = _merge_aux(aux_acc, aux)
+    return bx, new_entries, aux_acc
+
+
 def apply_stack_decode(
     params: Dict,
     x: jax.Array,
@@ -472,36 +521,42 @@ def apply_stack_decode(
     ``{"store": {...}, "tables": {"pos{i}": {"ids": [R, S+1], "slot":
     [R, E]}}}`` from ``core.expertpool``: per-block resident tables ride
     the scan as xs while the slab store is a loop constant, so MoE layers
-    gather only resident slab rows (``core.moe.moe_resident``)."""
+    gather only resident slab rows (``core.moe.moe_resident``).
+
+    With ``page_table`` set, ``cache_blocks`` is the paged storage stacked
+    over the blocks; it rides the carry with the block index and every
+    layer updates it in place (the dense ring caches ride xs/ys)."""
     tables = expert_resident["tables"] if expert_resident is not None else None
     store = expert_resident["store"] if expert_resident is not None else None
-    xs = (params["blocks"], cache_blocks)
+    xs = (params["blocks"],)
     if tables is not None:
         xs = xs + (tables,)
 
-    def block_fn(carry_x, xs_):
-        if tables is not None:
-            block_params, cache_entry, tab = xs_
-        else:
-            (block_params, cache_entry), tab = xs_, None
-        bx = carry_x
-        new_entries = {}
-        aux_acc: Dict[str, jax.Array] = {}
-        for i, spec in enumerate(cfg.layer_pattern):
-            res = None
-            if tab is not None and spec.moe:
-                res = {**tab[f"pos{i}"], "store": store}
-            bx, ne, aux = apply_layer_decode(
-                block_params[f"pos{i}"], bx, spec, cfg, topo, angles,
-                cache_entry[f"pos{i}"], lengths, expert_mask=expert_mask,
-                page_table=page_table, page_size=page_size,
-                expert_resident=res,
+    if page_table is not None:
+        def paged_block_fn(carry, xs_):
+            bx, pages, blk = carry
+            tab = xs_[1] if tables is not None else None
+            bx, pages, aux_acc = _decode_block(
+                xs_[0], bx, pages, tab, store, cfg, topo, angles, lengths,
+                expert_mask, page_table, page_size, blk,
             )
-            new_entries[f"pos{i}"] = ne
-            aux_acc = _merge_aux(aux_acc, aux)
-        return bx, (new_entries, aux_acc)
+            return (bx, pages, blk + 1), aux_acc
 
-    x, (new_cache, aux_stack) = jax.lax.scan(block_fn, x, xs)
+        x, new_cache, aux_stack = _scan_paged(
+            paged_block_fn, x, cache_blocks, xs
+        )
+    else:
+        def block_fn(carry_x, xs_):
+            tab = xs_[2] if tables is not None else None
+            bx, ne, aux_acc = _decode_block(
+                xs_[0], carry_x, xs_[1], tab, store, cfg, topo, angles,
+                lengths, expert_mask, None, page_size, None,
+            )
+            return bx, (ne, aux_acc)
+
+        x, (new_cache, aux_stack) = jax.lax.scan(
+            block_fn, x, (xs[0], cache_blocks) + xs[1:]
+        )
     # reduce over the block axis only: scalar aux stays scalar, measured
     # routing statistics (expert_frac [E] / group_frac [K]) keep their shape
     aux = {k: v.sum(axis=0) for k, v in aux_stack.items()}
@@ -530,27 +585,26 @@ def apply_stack_prefill_chunk(
     against the slot's mapped pages directly (``attn.paged_chunk_attention``
     — no gathered ring view) — so a prompt streams through one compiled
     trace per *chunk shape*, never one per prompt length, and the chunk
-    leaves exactly the pages a whole-prompt prefill would have left.
-    Returns (x [B, C, d], new_page_blocks)."""
+    leaves exactly the pages a whole-prompt prefill would have left.  The
+    stacked storage rides the scan's carry with the block index and is
+    written in place.  Returns (x [B, C, d], new_page_blocks)."""
     C = x.shape[1]
     valid = jnp.arange(C)[None, :] < n_valid[:, None]  # [B, C]
     last_pos = positions[:, 0] + n_valid - 1  # [B] final real position
     tables = expert_resident["tables"] if expert_resident is not None else None
     store = expert_resident["store"] if expert_resident is not None else None
-    xs = (params["blocks"], page_blocks)
+    xs = (params["blocks"],)
     if tables is not None:
         xs = xs + (tables,)
 
-    def block_fn(carry_x, xs_):
-        if tables is not None:
-            block_params, cache_entry, tab = xs_
-        else:
-            (block_params, cache_entry), tab = xs_, None
-        bx = carry_x
+    def block_fn(carry, xs_):
+        bx, pages, blk = carry
+        block_params = xs_[0]
+        tab = xs_[1] if tables is not None else None
         new_entries = {}
         for i, spec in enumerate(cfg.layer_pattern):
             p = block_params[f"pos{i}"]
-            ce = cache_entry[f"pos{i}"]
+            ce = pages[f"pos{i}"]
             h = rms_norm(bx, p["norm1"], cfg.norm_eps)
             with jax.named_scope("attention"):
                 q, k, v = attn.project_qkv(p["attn"], h, cfg, angles)
@@ -559,11 +613,11 @@ def apply_stack_prefill_chunk(
                 with jax.named_scope("kv_write"):
                     kc, vc, ksc, vsc = kvcache.paged_write_tokens_quant(
                         ce["k"], ce["v"], ce["k_scale"], ce["v_scale"], k, v,
-                        page_table, positions, valid, page_size,
+                        page_table, positions, valid, page_size, blk,
                     )
                 with jax.named_scope("attention"):
                     o = attn.paged_chunk_attention(
-                        q, kc, vc, page_table, positions, last_pos,
+                        q, kc, vc, blk, page_table, positions, last_pos,
                         window=cfg.sliding_window, k_scale=ksc, v_scale=vsc,
                     )
                 entry_out = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
@@ -571,11 +625,11 @@ def apply_stack_prefill_chunk(
                 with jax.named_scope("kv_write"):
                     kc, vc = kvcache.paged_write_tokens(
                         ce["k"], ce["v"], k, v, page_table, positions, valid,
-                        page_size,
+                        page_size, blk,
                     )
                 with jax.named_scope("attention"):
                     o = attn.paged_chunk_attention(
-                        q, kc, vc, page_table, positions, last_pos,
+                        q, kc, vc, blk, page_table, positions, last_pos,
                         window=cfg.sliding_window,
                     )
                 entry_out = {"k": kc, "v": vc}
@@ -596,9 +650,9 @@ def apply_stack_prefill_chunk(
                         y = apply_mlp(p["ffn"], h, cfg.act)
                 bx = bx + y
             new_entries[f"pos{i}"] = entry_out
-        return bx, new_entries
+        return (bx, new_entries, blk + 1), None
 
-    x, new_blocks = jax.lax.scan(block_fn, x, xs)
+    x, new_blocks, _ = _scan_paged(block_fn, x, page_blocks, xs)
     return x, new_blocks
 
 

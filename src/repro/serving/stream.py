@@ -229,7 +229,7 @@ class _SpillState:
     def __init__(self, entries: np.ndarray, blocks: Dict, length: int,
                  next_token: int, n_pages: int):
         self.entries = entries  # mapped ring entries (same for both tiers)
-        self.blocks = blocks  # pytree of [R_total, n_entries, ps, KV, hd]
+        self.blocks = blocks  # pytree of [R_total, n_entries, ps, KV*hd]
         self.length = length  # _slot_len at the safe point
         self.next_token = next_token  # pending token (KV not yet written)
         self.n_pages = n_pages  # original worst-case reservation
@@ -946,6 +946,11 @@ class EndCloudServingEngine(SlotEngineBase):
         gen = self._build_gen
 
         def counted(name, fn):
+            # every stage takes its tier's page storage third and returns
+            # it updated; the caller rebinds the returned storage.  The
+            # argument is not donated: the program copies each pool leaf
+            # once on entry and writes that copy in place, and a caller may
+            # still read the storage it passed
             return TraceCounter(
                 jax.jit(fn), self._traces.setdefault(name, set()), gen
             )

@@ -37,10 +37,12 @@ from repro.serving.stream import EndCloudServingEngine
 
 
 def _pool_case(lengths, *, ps=4, pps=4, num_pages=14, KV=2, hd=32, seed=0,
-               dtype=jnp.float32):
+               dtype=jnp.float32, n_layers=1):
     """Random pool + tables built through the real allocator: slot b holds
     positions [0, lengths[b]] (its current decode token included), mapped
-    exactly as the engines map them; untouched entries stay garbage."""
+    exactly as the engines map them; untouched entries stay garbage.  The
+    pools are a stack of ``n_layers`` blocks in the engines' lane-dense
+    layout, ``[n_layers, P+1, ps, KV*hd]``, different in every block."""
     rng = np.random.default_rng(seed)
     B = len(lengths)
     pool = PagePool(num_pages, ps, pps, n_slots=B)
@@ -48,21 +50,20 @@ def _pool_case(lengths, *, ps=4, pps=4, num_pages=14, KV=2, hd=32, seed=0,
         pool.reserve(b, kvcache.pages_needed(int(ln) + 1, ps, pps))
         pool.map_range(b, 0, int(ln) + 1)
     table = pool.device_rows(range(B))
-    pool_k = jnp.asarray(
-        rng.standard_normal((num_pages + 1, ps, KV, hd)), dtype
-    )
-    pool_v = jnp.asarray(
-        rng.standard_normal((num_pages + 1, ps, KV, hd)), dtype
-    )
+    shape = (n_layers, num_pages + 1, ps, KV * hd)
+    pool_k = jnp.asarray(rng.standard_normal(shape), dtype)
+    pool_v = jnp.asarray(rng.standard_normal(shape), dtype)
     return pool_k, pool_v, table
 
 
-def _dense_reference(q, pool_k, pool_v, table, q_positions, lengths, window):
-    """The pre-refactor path: materialize the ring via paged_gather, then
-    dense masked-softmax attention."""
-    W = table.shape[1] * pool_k.shape[1]
-    kbuf = kvcache.paged_gather(pool_k, table)
-    vbuf = kvcache.paged_gather(pool_v, table)
+def _dense_reference(q, pool_k, pool_v, table, q_positions, lengths, window,
+                     layer=0):
+    """The pre-refactor path: materialize the ring of block ``layer`` via
+    paged_gather, then dense masked-softmax attention."""
+    W = table.shape[1] * pool_k.shape[2]
+    KV = pool_k.shape[3] // q.shape[-1]
+    kbuf = kvcache.paged_gather(pool_k[layer], table, KV)
+    vbuf = kvcache.paged_gather(pool_v[layer], table, KV)
     key_pos = kvcache.ring_key_positions(lengths, W)
     if q.shape[1] == 1:
         return attn.decode_attention(
@@ -84,10 +85,10 @@ def test_decode_matches_dense_gather_oracle(window):
     ln = jnp.asarray(lengths, jnp.int32)
     want = _dense_reference(q, pool_k, pool_v, table, ln[:, None], ln, window)
     got_ref = paged_attention_ref(
-        q, pool_k, pool_v, table, ln[:, None], ln, window=window
+        q, pool_k, pool_v, 0, table, ln[:, None], ln, window=window
     )
     got_kernel = paged_attention(
-        q, pool_k, pool_v, table, ln[:, None], ln,
+        q, pool_k, pool_v, 0, table, ln[:, None], ln,
         window=window, interpret=True,
     )
     np.testing.assert_allclose(
@@ -114,10 +115,10 @@ def test_chunk_matches_chunk_attention(window):
     ln = jnp.asarray(last, jnp.int32)
     want = _dense_reference(q, pool_k, pool_v, table, positions, ln, window)
     got_ref = paged_attention_ref(
-        q, pool_k, pool_v, table, positions, ln, window=window
+        q, pool_k, pool_v, 0, table, positions, ln, window=window
     )
     got_kernel = paged_attention(
-        q, pool_k, pool_v, table, positions, ln,
+        q, pool_k, pool_v, 0, table, positions, ln,
         window=window, interpret=True,
     )
     valid_rows = np.arange(C)[None, :] < n_valid[:, None]  # [B, C]
@@ -141,14 +142,87 @@ def test_ring_wrap_matches_dense_gather_oracle():
         pool.reserve(b, pps)
         pool.map_range(b, 0, int(lengths[b]) + 1)
     table = pool.device_rows(range(B))
-    pool_k = jnp.asarray(rng.standard_normal((13, ps, 2, 32)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((13, ps, 2, 32)), jnp.float32)
+    pool_k = jnp.asarray(rng.standard_normal((1, 13, ps, 64)), jnp.float32)
+    pool_v = jnp.asarray(rng.standard_normal((1, 13, ps, 64)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, 1, 4, 32)), jnp.float32)
     ln = jnp.asarray(lengths, jnp.int32)
     want = _dense_reference(q, pool_k, pool_v, table, ln[:, None], ln, window)
     got = paged_attention(
-        q, pool_k, pool_v, table, ln[:, None], ln,
+        q, pool_k, pool_v, 0, table, ln[:, None], ln,
         window=window, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_kernel_reads_its_layer_of_the_stack(quantized, layer):
+    """The kernel attends block ``l`` of a stacked pool whose blocks all
+    hold different pages, read in place through the scalar-prefetched
+    layer operand, and equals the reference on that block alone: a stack
+    of one block, cut out beforehand.  bf16 pools, and int8 pools with
+    their per-token scale sidecars."""
+    R = 3
+    l = 0 if layer == "first" else R - 1
+    lengths = np.asarray([1, 6, 13], np.int64)
+    pool_k, pool_v, table = _pool_case(lengths, seed=11, n_layers=R)
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.standard_normal((3, 1, 4, 32)), jnp.bfloat16)
+    ln = jnp.asarray(lengths, jnp.int32)
+    if quantized:
+        def q8(pool):  # per-token int8 codes over the token's whole row
+            return kvcache.quantize_kv_tokens(pool[..., None, :])
+
+        (kq, ks), (vq, vs) = q8(pool_k), q8(pool_v)
+        kq, vq = kq[..., 0, :], vq[..., 0, :]
+        got = paged_attention(
+            q, kq, vq, l, table, ln[:, None], ln,
+            k_scale=ks, v_scale=vs, interpret=True,
+        )
+        want = paged_attention_ref(
+            q, kq[l:l + 1], vq[l:l + 1], 0, table, ln[:, None], ln,
+            k_scale=ks[l:l + 1], v_scale=vs[l:l + 1],
+        )
+    else:
+        pool_k, pool_v = (p.astype(jnp.bfloat16) for p in (pool_k, pool_v))
+        got = paged_attention(
+            q, pool_k, pool_v, l, table, ln[:, None], ln, interpret=True
+        )
+        want = paged_attention_ref(
+            q, pool_k[l:l + 1], pool_v[l:l + 1], 0, table, ln[:, None], ln
+        )
+    other = paged_attention_ref(
+        q, pool_k, pool_v, (l + 1) % R, table, ln[:, None], ln,
+        **({"k_scale": ks, "v_scale": vs} if quantized else {}),
+    )
+    got, want, other = (np.asarray(a, np.float32) for a in (got, want, other))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert np.abs(got - other).max() > 0.1  # another block reads otherwise
+
+
+@pytest.mark.parametrize(
+    "H,KV,hd",
+    [(6, 6, 64), (8, 4, 32), (6, 3, 128), (8, 2, 128)],
+    ids=["pairs", "one-tile", "wide-heads", "gqa-wide"],
+)
+def test_head_groups_match_the_reference(H, KV, hd):
+    """The kernel folds each 128-lane tile's heads into one block-diagonal
+    matmul (pairs of 64-wide heads, a row of four 32-wide heads in one
+    tile) or takes heads one at a time (128 wide); every layout equals
+    the reference on a two-query chunk with GQA rows."""
+    C = 2
+    last = np.asarray([1, 6, 14], np.int64)
+    pool_k, pool_v, table = _pool_case(last, KV=KV, hd=hd, seed=21)
+    rng = np.random.default_rng(22)
+    q = jnp.asarray(rng.standard_normal((3, C, H, hd)), jnp.float32)
+    positions = jnp.asarray(last[:, None] - C + 1 + np.arange(C), jnp.int32)
+    positions = jnp.maximum(positions, 0)
+    ln = jnp.asarray(last, jnp.int32)
+    want = paged_attention_ref(q, pool_k, pool_v, 0, table, positions, ln)
+    got = paged_attention(
+        q, pool_k, pool_v, 0, table, positions, ln, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -168,18 +242,18 @@ def test_garbage_pages_contribute_nothing():
     q = jnp.asarray(rng.standard_normal((2, 1, 4, 32)), jnp.float32)
     ln = jnp.asarray(lengths, jnp.int32)
     base = paged_attention(
-        q, pool_k, pool_v, table, ln[:, None], ln, interpret=True
+        q, pool_k, pool_v, 0, table, ln[:, None], ln, interpret=True
     )
-    poisoned_k = pool_k.at[-1].set(1e4)
-    poisoned_v = pool_v.at[-1].set(1e4)
+    poisoned_k = pool_k.at[:, -1].set(1e4)
+    poisoned_v = pool_v.at[:, -1].set(1e4)
     got = paged_attention(
-        q, poisoned_k, poisoned_v, table, ln[:, None], ln, interpret=True
+        q, poisoned_k, poisoned_v, 0, table, ln[:, None], ln, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
-    all_garbage = jnp.full_like(table, pool_k.shape[0] - 1)
+    all_garbage = jnp.full_like(table, pool_k.shape[1] - 1)
     zero = paged_attention(
-        q, poisoned_k, poisoned_v, all_garbage, ln[:, None], ln,
+        q, poisoned_k, poisoned_v, 0, all_garbage, ln[:, None], ln,
         interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(zero), 0.0)

@@ -15,7 +15,12 @@ Covers the tentpole invariants:
   (g) the number of compiled stage traces is bounded by chunk/group shapes,
       not by distinct prompt lengths;
   (h) download metering charges only active slots (regression);
-  (i) micro-batch groups are equal-sized (padded batch), one decode trace.
+  (i) micro-batch groups are equal-sized (padded batch), one decode trace;
+  (j) the stage programs do not consume the pools they are given: after a
+      call the storage passed in is still readable and unchanged, the
+      engines rebind the returned storage, and warm-up, spill/restore,
+      defrag and a replan re-split all serve the dense oracle's greedy
+      tokens.
 """
 
 import jax
@@ -447,3 +452,85 @@ def test_groups_are_equal_sized_with_padding(tiny_model):
     assert eng.slots[5] is None
     # equal groups -> exactly one compiled decode trace per tier
     assert eng.stage_trace_counts()["end_step"] == 1
+
+
+# ------------------------------------------ (j) pools are not consumed
+
+
+@pytest.mark.parametrize("kind", ["end_cloud", "single"])
+def test_served_tick_leaves_the_previous_pools_unchanged(tiny_model, kind):
+    """A served tick's stage calls return new storage and leave the storage
+    they were given as it was: the buffers the engine held before the tick
+    are live and unchanged after it, the engine holds the ones the calls
+    returned, which hold the tick's tokens, and it decodes on to the end."""
+    model, params = tiny_model
+    if kind == "end_cloud":
+        eng = EndCloudServingEngine(
+            model, params,
+            end_profile=PROFILES["a100"], cloud_profile=PROFILES["a100"],
+            max_batch=2, max_len=64, force_split=2, prefill_chunk=8,
+        )
+
+        def pools():
+            return jax.tree.leaves((eng._end_pages, eng._cloud_pages))
+    else:
+        eng = ServingEngine(model, params, max_batch=2, max_len=64,
+                            prefill_chunk=8)
+
+        def pools():
+            return jax.tree.leaves(eng.pages)
+    reqs = [Request(i, p, max_new_tokens=6)
+            for i, p in enumerate(_prompts(2, seed=12))]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()
+    before = pools()
+    kept = [np.asarray(leaf) for leaf in before]
+    eng.step()
+    after = pools()
+    assert not any(leaf.is_deleted() for leaf in before)
+    for leaf, want in zip(before, kept):
+        np.testing.assert_array_equal(np.asarray(leaf), want)
+    assert any(not np.array_equal(np.asarray(new), old)
+               for new, old in zip(after, kept))
+    eng.run()
+    assert all(r.done and len(r.generated) == 6 for r in reqs)
+
+
+def test_pools_through_spill_defrag_and_replan_match_dense_oracle(
+    tiny_model_f32,
+):
+    """Every path that rewrites a tier's storage outside a stage call —
+    warm-up, a preemption's spill and restore, and a replan's block re-split
+    with its defrag — keeps the stacked lane-dense pools consistent: greedy
+    tokens equal the dense oracle's (f32) and no page leaks."""
+    model, params = tiny_model_f32
+    prompts = _prompts(3, seed=13, lo=8, hi=14)
+    want = _dense_oracle(model, params, prompts, max_new_tokens=10)
+    weak_end = DeviceProfile("weak-end", peak_gflops=2.0, mem_gb=8.0,
+                             mem_bw_gbs=50.0, net_gbps=0.3)
+    eng = EndCloudServingEngine(
+        model, params,
+        end_profile=weak_end, cloud_profile=PROFILES["a100"],
+        max_batch=2, max_len=64, force_split=model.cfg.block_repeat,
+        prefill_chunk=8, admission="priority", preemption=True,
+    )
+    low = [Request(i, prompts[i], max_new_tokens=10, priority=2)
+           for i in (0, 1)]
+    urgent = Request(2, prompts[2], max_new_tokens=10, priority=0)
+    for r in low:
+        eng.submit(r)
+    for _ in range(200):
+        eng.step()
+        if all(len(r.generated) >= 3 for r in low):
+            break
+    eng.submit(urgent)
+    eng.step()
+    assert eng.n_preemptions == 1
+    eng.observe_bandwidth(weak_end.net_gbps)  # forces the off-optimal replan
+    eng.run()
+    assert eng.n_preempt_restores == 1
+    assert eng.replan_events and eng.split != model.cfg.block_repeat
+    got = {r.request_id: r.generated for r in low + [urgent]}
+    assert got == want
+    assert eng.end_pool.pages_in_use == 0 == eng.cloud_pool.pages_in_use

@@ -148,7 +148,8 @@ def test_quant_ops_kernel_matches_ref():
 def _quant_pool_case(lengths, *, ps=4, pps=4, num_pages=14, KV=2, hd=32,
                      seed=0):
     """Dense random pool -> (int8 pool + f16 sidecars, table) through the
-    real allocator, plus the dequantized dense-equivalent oracle pool."""
+    real allocator, as a one-block stack in the engines' lane-dense layout
+    (``[1, P+1, ps, KV*hd]``, sidecars ``[1, P+1, ps]``)."""
     rng = np.random.default_rng(seed)
     B = len(lengths)
     pool = PagePool(num_pages, ps, pps, n_slots=B)
@@ -162,7 +163,8 @@ def _quant_pool_case(lengths, *, ps=4, pps=4, num_pages=14, KV=2, hd=32,
                      jnp.float32)
     kq, ks = kvcache.quantize_kv_tokens(kd)
     vq, vs = kvcache.quantize_kv_tokens(vd)
-    return (kq, ks, vq, vs), table
+    rows = (1, num_pages + 1, ps, KV * hd)
+    return (kq.reshape(rows), ks[None], vq.reshape(rows), vs[None]), table
 
 
 @pytest.mark.parametrize("window", [None, 7])
@@ -177,14 +179,14 @@ def test_paged_attention_quant_kernel_vs_ref(window):
     k_deq = kvcache.dequantize_kv_pool(kq, ks, jnp.float32)
     v_deq = kvcache.dequantize_kv_pool(vq, vs, jnp.float32)
     want = paged_attention_ref(
-        q, k_deq, v_deq, table, ln[:, None], ln, window=window
+        q, k_deq, v_deq, 0, table, ln[:, None], ln, window=window
     )
     got_ref = paged_attention_ref(
-        q, kq, vq, table, ln[:, None], ln, window=window,
+        q, kq, vq, 0, table, ln[:, None], ln, window=window,
         k_scale=ks, v_scale=vs,
     )
     got_kernel = paged_attention(
-        q, kq, vq, table, ln[:, None], ln, window=window,
+        q, kq, vq, 0, table, ln[:, None], ln, window=window,
         k_scale=ks, v_scale=vs, interpret=True,
     )
     np.testing.assert_allclose(
@@ -209,9 +211,9 @@ def test_paged_attention_quant_chunk_kernel_vs_ref():
     ln = jnp.asarray(last, jnp.int32)
     k_deq = kvcache.dequantize_kv_pool(kq, ks, jnp.float32)
     v_deq = kvcache.dequantize_kv_pool(vq, vs, jnp.float32)
-    want = paged_attention_ref(q, k_deq, v_deq, table, positions, ln)
+    want = paged_attention_ref(q, k_deq, v_deq, 0, table, positions, ln)
     got = paged_attention(
-        q, kq, vq, table, positions, ln,
+        q, kq, vq, 0, table, positions, ln,
         k_scale=ks, v_scale=vs, interpret=True,
     )
     valid_rows = np.arange(C)[None, :] < n_valid[:, None]
